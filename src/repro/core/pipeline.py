@@ -43,7 +43,6 @@ them for compatibility.
 from __future__ import annotations
 
 import dataclasses
-import warnings
 from collections import deque
 from typing import Any, Optional
 
@@ -54,9 +53,6 @@ PyTree = Any
 
 
 # --------------------------------------------------------------- placement
-
-_HOST_PUT_UNAVAILABLE = False
-
 
 def _leaf_placements(tree: PyTree, memory_kind: str) -> PyTree:
     """Per-leaf sharding tree targeting ``memory_kind`` but PRESERVING each
@@ -89,33 +85,17 @@ def host_put(tree: PyTree, shardings: PyTree = None) -> PyTree:
     derived per leaf from the tree's CURRENT sharding (memory kind flipped
     to pinned_host) — see :func:`_leaf_placements`.
 
-    Backends without pinned_host support raise on the placement — only those
-    expected memory-kind errors are caught, and the FIRST one warns that the
-    state stays device-resident (the paper's offload memory saving does not
-    apply then).  Anything else propagates: silently keeping multi-GB
-    optimizer state on device would defeat the offload claim unnoticed."""
-    global _HOST_PUT_UNAVAILABLE
-    dev = jax.devices()[0]
-    if dev.platform == "cpu" or _HOST_PUT_UNAVAILABLE:
+    A backend that refuses pinned_host raises here: keeping multi-GB
+    optimizer state on device would silently undo the offload that HiFT's
+    memory claim rests on."""
+    if jax.devices()[0].platform == "cpu":
         return tree
-    try:
-        if shardings is not None:
-            host = jax.tree.map(lambda s: s.with_memory_kind("pinned_host"),
-                                shardings)
-        else:
-            host = _leaf_placements(tree, "pinned_host")
-        return jax.device_put(tree, host)
-    except (ValueError, NotImplementedError, RuntimeError) as e:
-        # the memory-kind errors backends actually raise: ValueError /
-        # XlaRuntimeError (a RuntimeError) for an unknown or unsupported
-        # memory kind, NotImplementedError from older plugin backends
-        _HOST_PUT_UNAVAILABLE = True
-        warnings.warn(
-            f"pinned_host offload unavailable on {dev.platform!r} ({e}); "
-            "optimizer state stays device-resident — the paper's offload "
-            "memory saving does not apply on this backend",
-            RuntimeWarning, stacklevel=2)
-        return tree
+    if shardings is not None:
+        host = jax.tree.map(lambda s: s.with_memory_kind("pinned_host"),
+                            shardings)
+    else:
+        host = _leaf_placements(tree, "pinned_host")
+    return jax.device_put(tree, host)
 
 
 def device_put_async(tree: PyTree, shardings: PyTree = None) -> PyTree:

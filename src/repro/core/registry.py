@@ -79,10 +79,12 @@ def make_runner(cfg, strategy: str = "hift", *, params: Any = None,
     - ``fused_update``: route the optimizer's elementwise update through the
       fused Pallas kernels (one VMEM pass over param+moments).  ``None``
       (default) auto-selects: fused on TPU for the GROUPED strategies
-      (whose group-sized trees the packed layout was sized for), unfused
-      elsewhere — the packing concatenates each dtype bucket into one
-      contiguous stream, so full-tree strategies like fpft pay transient
-      full-tree copies and must opt in explicitly.  Requires ``optimizer``
+      (whose group-sized trees the packed layout was sized for) without a
+      multi-device mesh, unfused elsewhere — the packing concatenates each
+      dtype bucket into one contiguous stream, so full-tree strategies like
+      fpft pay transient full-tree copies and must opt in explicitly, and
+      GSPMD cannot partition a compiled Pallas kernel over a mesh (the
+      sharded step would not compile).  Requires ``optimizer``
       given by NAME (one of ``FUSED_OPTIMIZERS``) so the factory can
       rebuild it.
     - ``pipeline_depth``: >= 2 pipelines the host<->device transfers
@@ -128,7 +130,8 @@ def make_runner(cfg, strategy: str = "hift", *, params: Any = None,
     quant = kwargs.pop("quant", None)
     grouped = strategy in ("hift", "hift_pipelined", "lisa")
     if isinstance(optimizer, str):
-        fused = (jax.default_backend() == "tpu" and grouped) \
+        fused = (jax.default_backend() == "tpu" and grouped
+                 and (mesh is None or mesh.size == 1)) \
             if fused_update is None else bool(fused_update)
         okw = {"use_pallas_fused": True} if (fused and
                                              optimizer in FUSED_OPTIMIZERS) \

@@ -794,6 +794,12 @@ class _GroupedStrategy(Strategy):
             if ins is not None:
                 active, frozen, bundle, batch = jax.device_put(
                     (active, frozen, bundle, batch), ins[:4])
+            else:
+                # commit every input where it lives: jit keys its cache on
+                # committedness, so an uncommitted first visit and a revisit
+                # whose bundle came back from host would compile twice
+                active, frozen, bundle, batch = device_put_async(
+                    (active, frozen, bundle, batch))
             new_active, new_bundle, loss = fn(active, frozen, bundle,
                                               batch, lr)
         if pipe is not None and next_gis:
@@ -1066,12 +1072,16 @@ class FPFTStrategy(Strategy):
         if self.policy.name in ("bf16",):
             params = tree_cast(params, self.policy.param_dtype)
         params = self.place_params(params)
-        opt_state = self.optimizer.init(params)
         if self.sharded:
-            opt_state = jax.device_put(
-                opt_state,
-                dist_shardings.opt_state_shardings(opt_state, params,
-                                                   self.mesh))
+            # born sharded: moments built on the default device first would
+            # need a whole unsharded copy there
+            shapes = jax.eval_shape(self.optimizer.init, params)
+            opt_state = jax.jit(
+                self.optimizer.init,
+                out_shardings=dist_shardings.opt_state_shardings(
+                    shapes, params, self.mesh))(params)
+        else:
+            opt_state = self.optimizer.init(params)
         extra = {}
         if self._cross_pod_on and self.cross_pod.compress:
             # per-pod EF residuals are training state: they checkpoint (and

@@ -1,5 +1,6 @@
-"""Pallas TPU flash-attention kernel (target: TPU v5e; validated with
-interpret=True on CPU against ref.flash_attention_ref).
+"""Pallas TPU flash-attention kernels (target: TPU v5e; validated in
+interpret mode on CPU against ``kernels.ref``).  ``interpret=None``
+resolves from the backend (``kernels.ops.default_interpret``).
 
 TPU adaptation of the CUDA flash algorithm:
   - grid = (B*H, S/block_q): each program owns one q block in VMEM and
@@ -20,6 +21,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels.ops import default_interpret
+
 
 def _flash_kernel(q_ref, k_ref, v_ref, o_ref, *, block_k: int, causal: bool,
                   scale: float, seq_len: int):
@@ -32,8 +35,8 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, *, block_k: int, causal: bool,
 
     def body(kj, carry):
         m, l, acc = carry
-        k_blk = pl.load(k_ref, (pl.dslice(kj * block_k, block_k), slice(None)))
-        v_blk = pl.load(v_ref, (pl.dslice(kj * block_k, block_k), slice(None)))
+        k_blk = k_ref[pl.ds(kj * block_k, block_k), :]
+        v_blk = v_ref[pl.ds(kj * block_k, block_k), :]
         s = q @ k_blk.astype(jnp.float32).T                      # (bq, bk) MXU
         if causal:
             q_pos = q_idx * block_q + jax.lax.iota(jnp.int32, block_q)[:, None]
@@ -61,11 +64,11 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, *, block_k: int, causal: bool,
 
 
 def flash_attention_pallas(q, k, v, *, causal: bool = True, block_q: int = 256,
-                           block_k: int = 256, interpret: bool = True):
+                           block_k: int = 256, interpret: bool = None):
     """q/k/v: (B, S, H, hd) (kv heads already repeated to H).
 
-    interpret=True runs the kernel body in Python on CPU (this container);
-    on TPU pass interpret=False for the compiled MXU path.
+    ``interpret=None`` compiles for the MXU on TPU and emulates the kernel
+    body elsewhere (``kernels.ops.default_interpret``).
     """
     b, s, h, hd = q.shape
     block_q = min(block_q, s)
@@ -91,7 +94,7 @@ def flash_attention_pallas(q, k, v, *, causal: bool = True, block_q: int = 256,
         ],
         out_specs=pl.BlockSpec((None, block_q, hd), lambda i, j: (i, j, 0)),
         out_shape=jax.ShapeDtypeStruct((b * h, s, hd), q.dtype),
-        interpret=interpret,
+        interpret=default_interpret(interpret),
     )(qr, kr, vr)
     return jnp.moveaxis(out.reshape(b, h, s, hd), 1, 2)
 
@@ -119,8 +122,8 @@ def _decode_kernel(starts_ref, lengths_ref, q_ref, k_ref, v_ref, o_ref, *,
 
     def body(kj, carry):
         m, l, acc = carry
-        k_blk = pl.load(k_ref, (pl.dslice(kj * block_k, block_k), slice(None)))
-        v_blk = pl.load(v_ref, (pl.dslice(kj * block_k, block_k), slice(None)))
+        k_blk = k_ref[pl.ds(kj * block_k, block_k), :]
+        v_blk = v_ref[pl.ds(kj * block_k, block_k), :]
         s = q @ k_blk.astype(jnp.float32).T                  # (1, bk)
         pos = kj * block_k + jax.lax.iota(jnp.int32, block_k)[None, :]
         s = jnp.where((pos >= start) & (pos < length), s, -1e30)
@@ -139,14 +142,14 @@ def _decode_kernel(starts_ref, lengths_ref, q_ref, k_ref, v_ref, o_ref, *,
 
 
 def flash_decode_pallas(q, k, v, lengths, starts=None, *, block_k: int = 128,
-                        interpret: bool = True):
+                        interpret: bool = None):
     """Single-query flash attention over a CONTIGUOUS KV cache.
 
     q: (B, H, hd); k/v: (B, S, KV, hd) with KV | H (GQA: each program picks
     its kv head by index, no HBM-side head repetition); lengths: (B,) int32
     — valid keys are positions ``[starts[b], lengths[b])``; ``starts=None``
     means no left-pad region.  Returns (B, H, hd).  Validated against
-    ``ref.flash_decode_ref``; interpret=True on CPU, compiled on TPU.
+    ``ref.flash_decode_ref``; interpreted on CPU, compiled on TPU.
     """
     b, s, kvh, hd = k.shape
     h = q.shape[1]
@@ -179,7 +182,7 @@ def flash_decode_pallas(q, k, v, lengths, starts=None, *, block_k: int = 128,
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b * h, 1, hd), q.dtype),
-        interpret=interpret,
+        interpret=default_interpret(interpret),
     )(starts.astype(jnp.int32), lengths.astype(jnp.int32), qr, kr, vr)
     return out.reshape(b, h, hd)
 
@@ -228,7 +231,7 @@ def _paged_decode_kernel(bt_ref, starts_ref, lengths_ref, q_ref, k_ref, v_ref,
 
 
 def paged_flash_decode_pallas(q, k_pool, v_pool, block_tables, lengths,
-                              starts=None, *, interpret: bool = True):
+                              starts=None, *, interpret: bool = None):
     """Single-query flash attention over a PAGED KV cache.
 
     q: (B, H, hd); k_pool/v_pool: (n_blocks, block_size, KV, hd) — the
@@ -282,7 +285,7 @@ def paged_flash_decode_pallas(q, k_pool, v_pool, block_tables, lengths,
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b * h, 1, hd), q.dtype),
-        interpret=interpret,
+        interpret=default_interpret(interpret),
     )(block_tables.astype(jnp.int32), starts.astype(jnp.int32),
       lengths.astype(jnp.int32), qr, kp, vp)
     return out.reshape(b, h, hd)

@@ -7,7 +7,7 @@ HBM sweeps for an unfused update.  Tiles are (8, 128)-aligned for the VPU;
 the shared layout/launch substrate lives in ``repro.kernels.ops``
 (``tile_layout`` pads so the grid always divides evenly, and the packed
 ``fused_adamw_update`` fuses a whole group into one launch per dtype
-bucket).  On compiled non-CPU backends the param/m/v inputs are DONATED
+bucket).  On the compiled path the param/m/v inputs are DONATED
 (``input_output_aliases``), so the update is in-place in HBM.
 """
 from __future__ import annotations

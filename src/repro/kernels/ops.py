@@ -1,11 +1,11 @@
 """Jit'd public wrappers for the Pallas kernels + the shared tiled-update
 substrate the fused optimizer kernels build on.
 
-On this CPU container interpret=True (XLA emulation of the kernel body);
-on TPU the same call sites compile to Mosaic.  ``INTERPRET`` flips globally
-and is the default every kernel resolves ``interpret=None`` against, so the
-HiFT hot loop selects the compiled path from the backend instead of
-hardcoding interpretation.
+Every kernel resolves ``interpret=None`` through :func:`default_interpret`,
+which reads the backend when the kernel is traced: compiled Mosaic on TPU,
+XLA emulation of the kernel body everywhere else.  So the HiFT hot loop
+selects the compiled path from the backend instead of hardcoding
+interpretation.
 
 The ``fused_*_update`` functions are the pytree-wide fused optimizer
 updates (AdamW / SGD-momentum / AdaGrad — the paper's three headline
@@ -22,13 +22,13 @@ from functools import lru_cache, partial
 import jax
 import jax.numpy as jnp
 
-INTERPRET = jax.default_backend() != "tpu"
-
 
 def default_interpret(interpret=None) -> bool:
-    """Resolve an ``interpret=None`` request from the backend: compiled
-    Mosaic on TPU, XLA interpretation everywhere else."""
-    return INTERPRET if interpret is None else bool(interpret)
+    """Resolve an ``interpret=None`` request from the backend at call time:
+    compiled Mosaic on TPU, XLA interpretation everywhere else."""
+    if interpret is None:
+        return jax.default_backend() != "tpu"
+    return bool(interpret)
 
 
 # --------------------------------------------------------- tiled substrate
@@ -80,8 +80,7 @@ def elementwise_update_call(kernel, tiled: list, scalars: list,
     grid loop costs ~10x more than the arithmetic it wraps, and there is no
     VMEM to respect).  ``donate`` maps input index -> output index through
     ``input_output_aliases`` so param/moment buffers update in place — on
-    compiled non-CPU backends only (interpret emulation and the CPU backend
-    keep functional copies)."""
+    the compiled path only (interpret emulation keeps functional copies)."""
     from jax.experimental import pallas as pl
 
     interpret = default_interpret(interpret)
@@ -98,8 +97,7 @@ def elementwise_update_call(kernel, tiled: list, scalars: list,
     sca = [jnp.asarray(s, jnp.float32).reshape(1) for s in scalars]
     tile = lambda: pl.BlockSpec((block_rows, 128), lambda i: (i, 0))
     scalar = lambda: pl.BlockSpec((1,), lambda i: (0,))
-    aliases = dict(donate) if (not interpret and
-                               jax.default_backend() != "cpu") else {}
+    aliases = {} if interpret else dict(donate)
     outs = pl.pallas_call(
         kernel,
         grid=grid,
@@ -200,7 +198,7 @@ def flash_attention(q, k, v, causal: bool = True, block_q: int = 256,
                     block_k: int = 256):
     from repro.kernels.flash_attention import flash_attention_pallas
     return flash_attention_pallas(q, k, v, causal=causal, block_q=block_q,
-                                  block_k=block_k, interpret=INTERPRET)
+                                  block_k=block_k)
 
 
 @jax.jit
@@ -208,10 +206,10 @@ def dequant_matmul(x, leaf):
     """``x @ dequantize(leaf)`` with the int8/NF4 decode fused into the
     matmul block — no materialized fp32 weight (kernels/fused_dequant_matmul)."""
     from repro.kernels.fused_dequant_matmul import fused_dequant_matmul
-    return fused_dequant_matmul(x, leaf, interpret=INTERPRET)
+    return fused_dequant_matmul(x, leaf)
 
 
 @partial(jax.jit, static_argnames=("chunk",))
 def ssm_scan(x, a_log, b, c, chunk: int = 128):
     from repro.kernels.ssm_scan import ssm_scan_pallas
-    return ssm_scan_pallas(x, a_log, b, c, chunk=chunk, interpret=INTERPRET)
+    return ssm_scan_pallas(x, a_log, b, c, chunk=chunk)

@@ -16,6 +16,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels.ops import default_interpret
+
 
 def _ssm_kernel(x_ref, a_ref, b_ref, c_ref, y_ref, hf_ref, *, chunk: int,
                 seq_len: int):
@@ -25,11 +27,11 @@ def _ssm_kernel(x_ref, a_ref, b_ref, c_ref, y_ref, hf_ref, *, chunk: int,
     nc = seq_len // chunk
 
     def body(ci, h):
-        sl = pl.dslice(ci * chunk, chunk)
-        x = pl.load(x_ref, (sl, slice(None))).astype(jnp.float32)   # (Lc, P)
-        a = pl.load(a_ref, (sl, slice(None))).astype(jnp.float32)   # (Lc, 1)
-        b = pl.load(b_ref, (sl, slice(None))).astype(jnp.float32)   # (Lc, N)
-        c = pl.load(c_ref, (sl, slice(None))).astype(jnp.float32)   # (Lc, N)
+        sl = pl.ds(ci * chunk, chunk)
+        x = x_ref[sl, :].astype(jnp.float32)   # (Lc, P)
+        a = a_ref[sl, :].astype(jnp.float32)   # (Lc, 1)
+        b = b_ref[sl, :].astype(jnp.float32)   # (Lc, N)
+        c = c_ref[sl, :].astype(jnp.float32)   # (Lc, N)
 
         a_log = a[:, 0]
         cum = jnp.cumsum(a_log)                                     # (Lc,)
@@ -43,7 +45,7 @@ def _ssm_kernel(x_ref, a_ref, b_ref, c_ref, y_ref, hf_ref, *, chunk: int,
         # inter-chunk: contribution of the entering state
         decay_from_start = jnp.exp(cum)                             # (Lc,)
         y = y + decay_from_start[:, None] * (c @ h.T)               # (Lc, P)
-        pl.store(y_ref, (sl, slice(None)), y.astype(y_ref.dtype))
+        y_ref[sl, :] = y.astype(y_ref.dtype)
         # update state: h' = exp(total) h + sum_j exp(total-cum_j) b_j x_j
         total = cum[-1]
         decay_to_end = jnp.exp(total - cum)                         # (Lc,)
@@ -54,7 +56,8 @@ def _ssm_kernel(x_ref, a_ref, b_ref, c_ref, y_ref, hf_ref, *, chunk: int,
     hf_ref[...] = h
 
 
-def ssm_scan_pallas(x, a_log, b, c, *, chunk: int = 128, interpret: bool = True):
+def ssm_scan_pallas(x, a_log, b, c, *, chunk: int = 128,
+                    interpret: bool = None):
     """x: (B, S, H, P) pre-scaled inputs; a_log: (B, S, H) log decays;
     b/c: (B, S, N).  Returns (y (B,S,H,P), h_final (B,H,P,N)).
 
@@ -84,7 +87,7 @@ def ssm_scan_pallas(x, a_log, b, c, *, chunk: int = 128, interpret: bool = True)
                    pl.BlockSpec((None, P, N), lambda i: (i, 0, 0))],
         out_shape=[jax.ShapeDtypeStruct((B * H, S, P), x.dtype),
                    jax.ShapeDtypeStruct((B * H, P, N), jnp.float32)],
-        interpret=interpret,
+        interpret=default_interpret(interpret),
     )(xr, ar, br, cr)
     y = jnp.moveaxis(y.reshape(B, H, S, P), 1, 2)
     return y, hf.reshape(B, H, P, N)

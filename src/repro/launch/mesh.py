@@ -51,10 +51,7 @@ def init_distributed(coordinator: str, num_processes: int, process_id: int,
             os.environ["XLA_FLAGS"] = (
                 flags + " --xla_force_host_platform_device_count="
                 f"{int(local_device_count)}").strip()
-    try:
-        jax.config.update("jax_cpu_collectives_implementation", "gloo")
-    except Exception:  # pragma: no cover - very old jaxlib: env-var fallback
-        os.environ.setdefault("JAX_CPU_COLLECTIVES_IMPLEMENTATION", "gloo")
+    jax.config.update("jax_cpu_collectives_implementation", "gloo")
     jax.distributed.initialize(coordinator_address=coordinator,
                                num_processes=num_processes,
                                process_id=process_id)

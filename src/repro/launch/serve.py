@@ -15,6 +15,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs.registry import ARCH_IDS, get_config
+from repro.launch.compile_cache import setup_compile_cache
 from repro.models import get_family
 from repro.serve.engine import ContinuousServeEngine, ServeEngine
 from repro.serve.scheduler import ServeRequest
@@ -23,7 +24,8 @@ from repro.serve.scheduler import ServeRequest
 def main(argv=None):
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True)
-    ap.add_argument("--smoke", action="store_true", default=True)
+    ap.add_argument("--smoke", action="store_true",
+                    help="use the reduced config (CPU-sized)")
     ap.add_argument("--requests", type=int, default=4)
     ap.add_argument("--max-new", type=int, default=12)
     ap.add_argument("--max-len", type=int, default=96)
@@ -38,6 +40,7 @@ def main(argv=None):
                     help="mesh spec (e.g. 2x2) to serve sharded; same "
                          "grammar as the training launcher")
     args = ap.parse_args(argv)
+    setup_compile_cache()
 
     cfg = get_config(args.arch, smoke=args.smoke)
     fam = get_family(cfg)

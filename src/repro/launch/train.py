@@ -22,6 +22,7 @@ docs/sharding.md.)
 from __future__ import annotations
 
 import argparse
+import functools
 
 import jax
 
@@ -29,6 +30,7 @@ from repro.configs.registry import ARCH_IDS, PAPER_IDS, get_config
 from repro.core import (AdaLomoConfig, HiFTConfig, LiSAConfig, LOMOConfig,
                         LRSchedule, MeZOConfig, make_runner, registry)
 from repro.data.synthetic import DataConfig, PrefetchIterator, SyntheticLM
+from repro.launch.compile_cache import setup_compile_cache
 from repro.models import get_family
 from repro.optim.mixed_precision import get_policy
 from repro.train.loop import LoopConfig, train
@@ -106,6 +108,7 @@ def main(argv=None):
     ap.add_argument("--resume", default="none", choices=["none", "auto"])
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args(argv)
+    setup_compile_cache()
 
     if args.coordinator:
         if args.num_processes is None or args.process_id is None:
@@ -120,17 +123,22 @@ def main(argv=None):
 
     cfg = get_config(args.arch, smoke=args.smoke)
     fam = get_family(cfg)
-    params = fam.init(cfg, jax.random.PRNGKey(args.seed))
-    n = sum(x.size for x in jax.tree.leaves(params))
-    print(f"[{cfg.name}] {n/1e6:.1f}M params, family={cfg.family}")
-
+    init = functools.partial(fam.init, cfg)
     mesh = None
     if args.mesh:
+        from repro.dist.shardings import param_shardings
         from repro.launch.mesh import mesh_from_spec
         mesh = mesh_from_spec(args.mesh)
         print(f"mesh {dict(zip(mesh.axis_names, mesh.devices.shape))} over "
               f"{mesh.size}/{len(jax.devices())} "
               f"{jax.devices()[0].platform} devices")
+        # born sharded: a whole fp32 tree on the first device would not fit
+        # it beside that device's share of the run
+        shapes = jax.eval_shape(init, jax.random.PRNGKey(args.seed))
+        init = jax.jit(init, out_shardings=param_shardings(shapes, mesh))
+    params = init(jax.random.PRNGKey(args.seed))
+    n = sum(x.size for x in jax.tree.leaves(params))
+    print(f"[{cfg.name}] {n/1e6:.1f}M params, family={cfg.family}")
 
     strategy = "fpft" if args.fpft else args.strategy
     sched = LRSchedule(base_lr=args.lr, kind="cosine",
@@ -159,6 +167,9 @@ def main(argv=None):
             grad_clip=0.0 if args.grad_clip is None else args.grad_clip)
     runner = make_runner(cfg, strategy, params=params,
                          optimizer=args.optimizer, seed=args.seed, **kw)
+    # the runner holds its own (policy-cast, placed) copy; a cast or placed
+    # copy leaves this tree as a second full-size one on device 0
+    del params
     if strategy in ("hift", "hift_pipelined", "lisa"):
         print(f"{strategy} k={runner.k}, "
               f"peak trainable {runner.peak_trainable_params()/1e6:.2f}M "
@@ -196,6 +207,7 @@ def main(argv=None):
         ckpt_dir=args.ckpt_dir, log_every=max(args.steps // 10, 1),
         resume=args.resume))
     print(f"done: final loss {out['losses'][-1]:.4f}")
+    out["state"] = runner.state
     return out
 
 

@@ -151,7 +151,6 @@ def moe_ffn_spmd(p, x, cfg: ArchConfig):
     per layer.  This replaces the global sort-based dispatch, which GSPMD
     degenerates into replicated (N*K, d) gathers (hundreds of GB/device at
     1M tokens)."""
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
     from repro.dist import ctx as dctx
 
@@ -176,9 +175,9 @@ def moe_ffn_spmd(p, x, cfg: ArchConfig):
 
     bspec = P(daxes, None, None)
     espec = P(maxis, None, None)
-    fn = shard_map(body, mesh=mesh,
-                   in_specs=(bspec, P(None, None), espec, espec, espec),
-                   out_specs=bspec, check_rep=False)
+    fn = jax.shard_map(body, mesh=mesh,
+                       in_specs=(bspec, P(None, None), espec, espec, espec),
+                       out_specs=bspec, check_vma=False)
     out = fn(x, p["router"], p["w_gate"], p["w_up"], p["w_down"])
     if cfg.n_shared_experts > 0:
         xt = x.reshape(b * s, d)

@@ -104,5 +104,6 @@ def train(runner, data_iter, loop_cfg: LoopConfig,
         # skipped when total_steps landed exactly on a ckpt_every boundary
         ckpt.save(loop_cfg.ckpt_dir, loop_cfg.total_steps, runner.state_dict(),
                   keep=loop_cfg.keep, async_write=False)
-    return {"losses": losses, "stragglers": watchdog.flagged,
+    return {"losses": losses, "step_times": watchdog.times,
+            "stragglers": watchdog.flagged,
             "final_step": loop_cfg.total_steps}

@@ -3,7 +3,7 @@ full-parameter step vs resident ``fpft`` — BIT-identical states; streaming
 may only move WHERE the optimizer state lives, never what the update
 computes — plus checkpoint interchangeability, the make_runner knob
 threading, the stream-safety gates, and the error paths of every stream
-surface (StreamConfig / ChunkLayout / BundlePipeline / host_put fallback /
+surface (StreamConfig / ChunkLayout / BundlePipeline / host_put /
 the fused strategies' cross_pod rejection).
 
 The registry entry ``fpft_streamed`` additionally rides the full strategy
@@ -11,8 +11,6 @@ conformance battery (tests/test_strategy_conformance.py) with zero
 carve-outs; the hypothesis layout sweep lives in
 tests/test_chunk_properties.py.
 """
-import warnings
-
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -181,16 +179,15 @@ def test_lomo_adalomo_reject_cross_pod_with_exact_message():
             "hift/lisa — for compressed cross-pod data parallelism")
 
 
-def test_host_put_warns_once_then_falls_back(monkeypatch):
-    """On a backend without pinned_host the FIRST failed offload warns and
-    flips the module latch; later calls fall back silently (state stays
-    device-resident) instead of re-raising or re-warning per bundle."""
+def test_host_put_raises_when_pinned_host_refused(monkeypatch):
+    """On a non-CPU backend that refuses the pinned_host memory kind the
+    offload fails loudly, every time: silently keeping optimizer bundles
+    device-resident would undo HiFT's memory saving unnoticed."""
     tree = {"w": jnp.ones((4,))}
 
     class FakeDev:
         platform = "faketpu"
 
-    monkeypatch.setattr(pipeline, "_HOST_PUT_UNAVAILABLE", False)
     monkeypatch.setattr(pipeline.jax, "devices", lambda: [FakeDev()])
     # the placement derivation needs real Device objects; the failure under
     # test is the backend rejecting the pinned_host memory kind at put time
@@ -201,9 +198,6 @@ def test_host_put_warns_once_then_falls_back(monkeypatch):
         raise ValueError("unknown memory kind 'pinned_host'")
 
     monkeypatch.setattr(pipeline.jax, "device_put", boom)
-    with pytest.warns(RuntimeWarning, match="pinned_host offload unavailable"):
-        assert pipeline.host_put(tree) is tree
-    assert pipeline._HOST_PUT_UNAVAILABLE is True
-    with warnings.catch_warnings():
-        warnings.simplefilter("error")          # a second warn would raise
-        assert pipeline.host_put(tree) is tree
+    for _ in range(2):
+        with pytest.raises(ValueError, match="pinned_host"):
+            pipeline.host_put(tree)
