@@ -8,7 +8,6 @@ Prints ``name,us_per_call,derived`` CSV rows.
 
   memory_table         -> paper Tables 8-12 + Appendix-B equations
   trainable_params     -> paper Fig. 6(e)
-  speed_table          -> paper Table 5 (steps/s HiFT vs FPFT)
   strategy_equivalence -> paper Fig. 4 (order + grouping ablations)
   convergence          -> paper Fig. 3 (loss stability)
   roofline             -> §Roofline report from the dry-run artifacts
@@ -21,11 +20,11 @@ import traceback
 
 def main() -> None:
     print("name,us_per_call,derived")
-    from benchmarks import (convergence, memory_table, roofline, speed_table,
+    from benchmarks import (convergence, memory_table, roofline,
                             strategy_equivalence, trainable_params)
     ok = True
     for mod in [memory_table, trainable_params, strategy_equivalence,
-                convergence, speed_table, roofline]:
+                convergence, roofline]:
         try:
             mod.run(csv=True)
         except Exception as e:

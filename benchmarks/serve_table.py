@@ -11,8 +11,7 @@ instead of draining the batch, and the paged cache admits mixed-length
 requests into a pool a contiguous cache of the same capacity could not.
 
 Alongside the printed CSV the numbers land machine-readable in
-``BENCH_serve.json`` (override with ``--out``) — uploaded from CI next to
-``BENCH_speed.json``.
+``BENCH_serve.json`` (override with ``--out``), uploaded from CI.
 """
 from __future__ import annotations
 

@@ -74,6 +74,7 @@ from typing import Any, Callable, Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.common.pytree import tree_cast, tree_size
 from repro.dist import ctx as dist_ctx
@@ -598,8 +599,9 @@ class _GroupedStrategy(Strategy):
         self.k = len(self.groups)
         self.memory_m = m
         # per-group caches: gi -> (jitted step, in_shardings|None) and
-        # ("wb", gi) -> jitted sharded write_back
+        # ("wb", gi) -> jitted sharded write_back; gi -> the bundle's bytes
         self._step_fns: dict[Any, tuple[Callable, Any]] = {}
+        self._bundle_bytes: dict[int, int] = {}
         self._pipeline: Optional[BundlePipeline] = None
 
     def _setup_pipeline(self, depth: int) -> None:
@@ -708,21 +710,23 @@ class _GroupedStrategy(Strategy):
             else:
                 loss, grads = jax.value_and_grad(loss_of)(work, batch)
                 ef = {}
-            if qf is not None:
-                new_master, new_st = opt.update(grads, bundle["opt"],
-                                                bundle["master"], lr)
-                new_active = quantize_tree(
-                    tree_cast(new_master, policy.param_dtype), qf)
-                return new_active, {"opt": new_st, "master": new_master,
-                                    **ef}, loss
-            if policy.master_active_group_only:
-                master, st = bundle["master"], bundle["opt"]
-                new_master, new_st = opt.update(grads, st, master, lr)
-                new_active = tree_cast(new_master, policy.param_dtype)
-                return new_active, {"opt": new_st, "master": new_master,
-                                    **ef}, loss
-            new_active, new_st = opt.update(grads, bundle["opt"], active, lr)
-            return new_active, {"opt": new_st, **ef}, loss
+            with jax.named_scope("update"):
+                if qf is not None:
+                    new_master, new_st = opt.update(grads, bundle["opt"],
+                                                    bundle["master"], lr)
+                    new_active = quantize_tree(
+                        tree_cast(new_master, policy.param_dtype), qf)
+                    return new_active, {"opt": new_st, "master": new_master,
+                                        **ef}, loss
+                if policy.master_active_group_only:
+                    master, st = bundle["master"], bundle["opt"]
+                    new_master, new_st = opt.update(grads, st, master, lr)
+                    new_active = tree_cast(new_master, policy.param_dtype)
+                    return new_active, {"opt": new_st, "master": new_master,
+                                        **ef}, loss
+                new_active, new_st = opt.update(grads, bundle["opt"], active,
+                                                lr)
+                return new_active, {"opt": new_st, **ef}, loss
 
         if self.sharded and example is not None:
             ins, outs = dist_shardings.group_step_shardings(
@@ -770,16 +774,25 @@ class _GroupedStrategy(Strategy):
             return None
         return dist_shardings.bundle_shardings(bundle, self.mesh)
 
+    def _nbytes(self, gi: int, bundle: PyTree) -> int:
+        """The bytes of group ``gi``'s bundle, summed once per group: a
+        bundle keeps its leaves' shapes and dtypes from visit to visit."""
+        if gi not in self._bundle_bytes:
+            self._bundle_bytes[gi] = sum(x.nbytes
+                                         for x in jax.tree.leaves(bundle))
+        return self._bundle_bytes[gi]
+
     def _group_step(self, state: TrainState, batch, gi: int, lr: float,
                     next_gis: Optional[list] = None
                     ) -> tuple[PyTree, PyTree, jnp.ndarray]:
         group = self.groups[gi]
-        active, frozen = split_params(state.params, group)
         key = str(gi)
         bundle = state.opt_state.get(key)
         fresh = bundle is None
-        if fresh:
-            bundle = self._init_bundle(active)
+        with TraceAnnotation("repro.split", group=gi):
+            active, frozen = split_params(state.params, group)
+            if fresh:
+                bundle = self._init_bundle(active)
         lr = jnp.asarray(lr, jnp.float32)
         pipe = self._pipeline
         with self._trace_ctx():
@@ -789,19 +802,25 @@ class _GroupedStrategy(Strategy):
                 # host -> device; sharded bundles keep their partitioning and
                 # only change memory kind.  Pipelined, this is usually a
                 # cache hit on the copy prefetched during the PREVIOUS step.
-                bundle = (pipe.fetch(key, bundle, bspec) if pipe is not None
-                          else device_put_async(bundle, bspec))
-            if ins is not None:
-                active, frozen, bundle, batch = jax.device_put(
-                    (active, frozen, bundle, batch), ins[:4])
-            else:
-                # commit every input where it lives: jit keys its cache on
-                # committedness, so an uncommitted first visit and a revisit
-                # whose bundle came back from host would compile twice
-                active, frozen, bundle, batch = device_put_async(
-                    (active, frozen, bundle, batch))
-            new_active, new_bundle, loss = fn(active, frozen, bundle,
-                                              batch, lr)
+                with TraceAnnotation("repro.bundle_fetch", group=gi,
+                                     bytes=self._nbytes(gi, bundle)):
+                    bundle = (pipe.fetch(key, bundle, bspec)
+                              if pipe is not None
+                              else device_put_async(bundle, bspec))
+            with TraceAnnotation("repro.place_inputs", group=gi):
+                if ins is not None:
+                    active, frozen, bundle, batch = jax.device_put(
+                        (active, frozen, bundle, batch), ins[:4])
+                else:
+                    # commit every input where it lives: jit keys its cache
+                    # on committedness, so an uncommitted first visit and a
+                    # revisit whose bundle came back from host would compile
+                    # twice
+                    active, frozen, bundle, batch = device_put_async(
+                        (active, frozen, bundle, batch))
+            with TraceAnnotation("repro.dispatch", group=gi):
+                new_active, new_bundle, loss = fn(active, frozen, bundle,
+                                                  batch, lr)
         if pipe is not None and next_gis:
             # the step above is DISPATCHED, not done: start the upcoming
             # groups' uploads now so they overlap this step's compute.  With
@@ -811,22 +830,28 @@ class _GroupedStrategy(Strategy):
             # have no bundle yet (the step inits one) — nothing to prefetch;
             # revisits of gi inside the window are skipped (its bundle is
             # the one this step is updating).
-            seen = {gi}
-            for ngi in next_gis:
-                if ngi in seen:
-                    continue
-                seen.add(ngi)
-                nbundle = state.opt_state.get(str(ngi))
-                if nbundle is not None and not pipe.holds(str(ngi), nbundle):
-                    pipe.prefetch(str(ngi), nbundle,
-                                  self._bundle_placement(nbundle))
+            with TraceAnnotation("repro.bundle_prefetch", group=gi):
+                seen = {gi}
+                for ngi in next_gis:
+                    if ngi in seen:
+                        continue
+                    seen.add(ngi)
+                    nbundle = state.opt_state.get(str(ngi))
+                    if nbundle is not None and not pipe.holds(str(ngi),
+                                                              nbundle):
+                        pipe.prefetch(str(ngi), nbundle,
+                                      self._bundle_placement(nbundle))
         if self.offload_optimizer:
-            new_bundle = (pipe.offload(key, new_bundle, bspec)
-                          if pipe is not None
-                          else host_put(new_bundle, bspec))
+            with TraceAnnotation("repro.bundle_offload", group=gi,
+                                 bytes=self._nbytes(gi, new_bundle)):
+                new_bundle = (pipe.offload(key, new_bundle, bspec)
+                              if pipe is not None
+                              else host_put(new_bundle, bspec))
         opt_state = dict(state.opt_state)
         opt_state[key] = new_bundle
-        return self._write_back(gi, state.params, new_active), opt_state, loss
+        with TraceAnnotation("repro.write_back", group=gi):
+            params = self._write_back(gi, state.params, new_active)
+        return params, opt_state, loss
 
     def peak_trainable_params(self, params: PyTree) -> int:
         if self._quant_frozen is not None:
@@ -1004,7 +1029,9 @@ def fpft_step_body(cfg, optimizer: Optimizer, policy: Policy = FP32,
             return loss_fn(cfg, p, batch, compute_dtype=policy.compute_dtype)
 
         loss, grads = jax.value_and_grad(loss_of)(params)
-        new_params, new_state = optimizer.update(grads, opt_state, params, lr)
+        with jax.named_scope("update"):
+            new_params, new_state = optimizer.update(grads, opt_state, params,
+                                                     lr)
         return new_params, new_state, loss
 
     return step
@@ -1131,9 +1158,11 @@ class FPFTStrategy(Strategy):
                                     batch))
                 args = (state.params, state.opt_state, residuals, batch)
                 if ins is not None:
-                    args = jax.device_put(args, ins[:4])
-                params, opt_state, new_res, loss = fn(
-                    *args, jnp.asarray(lr, jnp.float32))
+                    with TraceAnnotation("repro.place_inputs"):
+                        args = jax.device_put(args, ins[:4])
+                with TraceAnnotation("repro.dispatch"):
+                    params, opt_state, new_res, loss = fn(
+                        *args, jnp.asarray(lr, jnp.float32))
             extra = dict(state.extra or {})
             if self.cross_pod.compress:
                 extra["ef_residual"] = new_res
@@ -1143,8 +1172,11 @@ class FPFTStrategy(Strategy):
             fn, ins = self._fn((state.params, state.opt_state, batch))
             args = (state.params, state.opt_state, batch)
             if ins is not None:
-                args = jax.device_put(args, ins[:3])
-            params, opt_state, loss = fn(*args, jnp.asarray(lr, jnp.float32))
+                with TraceAnnotation("repro.place_inputs"):
+                    args = jax.device_put(args, ins[:3])
+            with TraceAnnotation("repro.dispatch"):
+                params, opt_state, loss = fn(*args,
+                                             jnp.asarray(lr, jnp.float32))
         new_state = TrainState(params, opt_state, step + 1, state.extra)
         return new_state, {"loss": loss, "lr": lr, "strategy": self.name}
 
@@ -2286,7 +2318,10 @@ class Runner:
     # -------------------------------------------------------------- step
 
     def train_step(self, batch) -> jnp.ndarray:
-        self.state, self.last_metrics = self.strategy.step(self.state, batch)
+        with TraceAnnotation("repro.train_step", step=self.step_count):
+            state, self.last_metrics = self.strategy.step(self.state, batch)
+            with TraceAnnotation("repro.release"):
+                self.state = state         # frees the previous state's arrays
         return self.last_metrics["loss"]
 
     def lr_for_step(self, step: Optional[int] = None) -> float:

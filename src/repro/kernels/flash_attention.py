@@ -95,6 +95,7 @@ def flash_attention_pallas(q, k, v, *, causal: bool = True, block_q: int = 256,
         out_specs=pl.BlockSpec((None, block_q, hd), lambda i, j: (i, j, 0)),
         out_shape=jax.ShapeDtypeStruct((b * h, s, hd), q.dtype),
         interpret=default_interpret(interpret),
+        name="flash_attention",
     )(qr, kr, vr)
     return jnp.moveaxis(out.reshape(b, h, s, hd), 1, 2)
 
@@ -183,6 +184,7 @@ def flash_decode_pallas(q, k, v, lengths, starts=None, *, block_k: int = 128,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b * h, 1, hd), q.dtype),
         interpret=default_interpret(interpret),
+        name="flash_decode",
     )(starts.astype(jnp.int32), lengths.astype(jnp.int32), qr, kr, vr)
     return out.reshape(b, h, hd)
 
@@ -286,6 +288,7 @@ def paged_flash_decode_pallas(q, k_pool, v_pool, block_tables, lengths,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b * h, 1, hd), q.dtype),
         interpret=default_interpret(interpret),
+        name="paged_flash_decode",
     )(block_tables.astype(jnp.int32), starts.astype(jnp.int32),
       lengths.astype(jnp.int32), qr, kp, vp)
     return out.reshape(b, h, hd)

@@ -15,7 +15,7 @@ import jax.numpy as jnp
 from repro.kernels.ops import elementwise_update_call
 
 
-def _adagrad_kernel(p_ref, g_ref, a_ref, lr_ref, po_ref, ao_ref, *,
+def _fused_adagrad_kernel(p_ref, g_ref, a_ref, lr_ref, po_ref, ao_ref, *,
                     eps, weight_decay):
     p32 = p_ref[...].astype(jnp.float32)
     g32 = g_ref[...].astype(jnp.float32) + weight_decay * p32
@@ -33,7 +33,7 @@ def fused_adagrad_pallas(p, g, accum, *, lr, eps=1e-10, weight_decay=0.0,
     ``fused_adamw_pallas`` (param + accumulator donated on compiled
     backends)."""
     shape, dtype = p.shape, p.dtype
-    kernel = functools.partial(_adagrad_kernel, eps=eps,
+    kernel = functools.partial(_fused_adagrad_kernel, eps=eps,
                                weight_decay=weight_decay)
     po, ao = elementwise_update_call(
         kernel,

@@ -19,7 +19,7 @@ import jax.numpy as jnp
 from repro.kernels.ops import elementwise_update_call
 
 
-def _adamw_kernel(p_ref, g_ref, m_ref, v_ref, lr_ref, c1_ref, c2_ref,
+def _fused_adamw_kernel(p_ref, g_ref, m_ref, v_ref, lr_ref, c1_ref, c2_ref,
                   po_ref, mo_ref, vo_ref, *, b1, b2, eps, weight_decay):
     g = g_ref[...].astype(jnp.float32)
     # moments load in their RESIDENT dtype and dequantize (astype) in VMEM —
@@ -48,7 +48,7 @@ def fused_adamw_pallas(p, g, m, v, *, lr, b1=0.9, b2=0.999, eps=1e-8,
     quantized residency): the kernel dequantizes into the update and
     re-rounds on store, so no fp32 moment copy is ever materialized."""
     shape, dtype = p.shape, p.dtype
-    kernel = functools.partial(_adamw_kernel, b1=b1, b2=b2, eps=eps,
+    kernel = functools.partial(_fused_adamw_kernel, b1=b1, b2=b2, eps=eps,
                                weight_decay=weight_decay)
     po, mo, vo = elementwise_update_call(
         kernel,

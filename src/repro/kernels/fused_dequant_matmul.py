@@ -110,5 +110,6 @@ def fused_dequant_matmul(x, leaf, *, block_m: int = None,
         out_specs=pl.BlockSpec((block_m, _LANE), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((mp, np_), x.dtype),
         interpret=interpret,
+        name="fused_dequant_matmul",
     )(xp, qp, s)
     return out[:m, :n]

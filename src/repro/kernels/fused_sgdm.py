@@ -15,7 +15,7 @@ import jax.numpy as jnp
 from repro.kernels.ops import elementwise_update_call
 
 
-def _sgdm_kernel(p_ref, g_ref, mu_ref, lr_ref, po_ref, muo_ref, *,
+def _fused_sgdm_kernel(p_ref, g_ref, mu_ref, lr_ref, po_ref, muo_ref, *,
                  momentum, weight_decay):
     p32 = p_ref[...].astype(jnp.float32)
     g32 = g_ref[...].astype(jnp.float32) + weight_decay * p32
@@ -32,7 +32,7 @@ def fused_sgdm_pallas(p, g, mu, *, lr, momentum=0.9, weight_decay=0.0,
     ``fused_adamw_pallas`` (param + momentum donated on compiled
     backends)."""
     shape, dtype = p.shape, p.dtype
-    kernel = functools.partial(_sgdm_kernel, momentum=momentum,
+    kernel = functools.partial(_fused_sgdm_kernel, momentum=momentum,
                                weight_decay=weight_decay)
     po, muo = elementwise_update_call(
         kernel,

@@ -80,7 +80,10 @@ def elementwise_update_call(kernel, tiled: list, scalars: list,
     grid loop costs ~10x more than the arithmetic it wraps, and there is no
     VMEM to respect).  ``donate`` maps input index -> output index through
     ``input_output_aliases`` so param/moment buffers update in place — on
-    the compiled path only (interpret emulation keeps functional copies)."""
+    the compiled path only (interpret emulation keeps functional copies).
+    The call is named after the kernel function (``_fused_adamw_kernel``
+    -> ``fused_adamw_kernel``), which is how a trace tells the fused
+    updates apart."""
     from jax.experimental import pallas as pl
 
     interpret = default_interpret(interpret)
@@ -106,6 +109,7 @@ def elementwise_update_call(kernel, tiled: list, scalars: list,
         out_shape=[jax.ShapeDtypeStruct((rows, 128), dt) for dt in out_dtypes],
         input_output_aliases=aliases,
         interpret=interpret,
+        name=getattr(kernel, "func", kernel).__name__.lstrip("_"),
     )(*bufs, *sca)
     return [o.reshape(-1)[:n] for o in outs]
 

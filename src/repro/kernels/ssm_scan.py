@@ -88,6 +88,7 @@ def ssm_scan_pallas(x, a_log, b, c, *, chunk: int = 128,
         out_shape=[jax.ShapeDtypeStruct((B * H, S, P), x.dtype),
                    jax.ShapeDtypeStruct((B * H, P, N), jnp.float32)],
         interpret=default_interpret(interpret),
+        name="ssm_scan",
     )(xr, ar, br, cr)
     y = jnp.moveaxis(y.reshape(B, H, S, P), 1, 2)
     return y, hf.reshape(B, H, P, N)

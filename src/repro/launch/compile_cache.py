@@ -5,6 +5,12 @@ of a short run.  The entry points (``launch.train``, ``launch.serve``,
 ``chip_smoke.py``) call :func:`setup_compile_cache` once at startup, before
 the first compile.  The cache key includes its directory, so the directory
 is fixed: a path built from a temp name, a pid or a time would never hit.
+
+The key also includes each program's metadata (its name scopes and source
+lines).  Without it an executable loaded from the cache keeps the names of
+the source that first compiled it, and a profile of a changed program
+shows the old names: the benchmark reads the ``update`` scope and the
+backward's ``transpose(...)`` from those names.
 """
 from __future__ import annotations
 
@@ -21,11 +27,13 @@ def setup_compile_cache() -> str:
     that directory.
 
     Where ``JAX_COMPILATION_CACHE_DIR`` is set, JAX has already read it and
-    nothing is set here.  Otherwise the cache goes to :data:`DEFAULT_DIR`
-    inside the checkout."""
+    no directory is set here.  Otherwise the cache goes to
+    :data:`DEFAULT_DIR` inside the checkout.  Either way entries are keyed
+    on the programs' metadata too."""
+    import jax
+    jax.config.update("jax_compilation_cache_include_metadata_in_key", True)
     placed = os.environ.get(ENV_VAR)
     if placed:
         return placed
-    import jax
     jax.config.update("jax_compilation_cache_dir", str(DEFAULT_DIR))
     return str(DEFAULT_DIR)

@@ -141,22 +141,28 @@ def test_chip_smoke_refuses_a_cpu(chip_smoke):
 
 def test_compile_cache_placed_from_outside_sets_nothing(monkeypatch,
                                                          tmp_path):
+    """With the directory placed from outside, no directory is set: only
+    the key's policy (metadata in the key) is."""
     monkeypatch.setenv(compile_cache.ENV_VAR, str(tmp_path))
-
-    def refuse(*args):
-        raise AssertionError(f"set {args} with {compile_cache.ENV_VAR} set")
-
-    monkeypatch.setattr(jax.config, "update", refuse)
+    updates = []
+    monkeypatch.setattr(jax.config, "update",
+                        lambda *args: updates.append(args))
     assert compile_cache.setup_compile_cache() == str(tmp_path)
+    assert updates == [("jax_compilation_cache_include_metadata_in_key",
+                        True)]
 
 
 def test_compile_cache_defaults_to_fixed_path_in_checkout(monkeypatch):
     monkeypatch.delenv(compile_cache.ENV_VAR, raising=False)
     before = jax.config.jax_compilation_cache_dir
+    keyed = jax.config.jax_compilation_cache_include_metadata_in_key
     try:
         path = compile_cache.setup_compile_cache()
         assert path == str(ROOT / ".jax_cache")
         assert jax.config.jax_compilation_cache_dir == path
+        assert jax.config.jax_compilation_cache_include_metadata_in_key
         assert compile_cache.setup_compile_cache() == path
     finally:
         jax.config.update("jax_compilation_cache_dir", before)
+        jax.config.update("jax_compilation_cache_include_metadata_in_key",
+                          keyed)

@@ -73,6 +73,39 @@ def test_fused_update_compiles_for_a_qwen2_block(one_chip, name):
     flat = jax.ShapeDtypeStruct((n,), jnp.float32, sharding=one_chip)
     compiled = _compile(fn, *[flat] * (2 + n_state))
     assert "tpu_custom_call" in compiled.as_text()
+    # the call carries the kernel's own name, which a trace shows
+    assert f"fused_{name}_kernel" in compiled.as_text()
+
+
+def test_hift_group_step_runs_the_named_fused_adamw_under_update(
+        one_chip, monkeypatch):
+    """A HiFT group step as ``make_runner`` builds it on a TPU: its update
+    is the fused AdamW kernel, named, inside the ``update`` scope."""
+    from repro.core import HiFTConfig, make_runner
+    from repro.core.grouping import split_params
+    from repro.kernels import ops
+
+    monkeypatch.setattr(ops, "default_interpret", lambda interpret=None:
+                        False)
+    st = make_runner(get_config("qwen2_0_5b", smoke=True), "hift",
+                     optimizer="adamw", fused_update=True,
+                     hift=HiFTConfig(m=1)).strategy
+    gi = 1                                    # layer 0
+    place = lambda t: jax.tree.map(lambda x: jax.ShapeDtypeStruct(
+        x.shape, x.dtype, sharding=one_chip), t)
+    params = jax.eval_shape(lambda: get_family(st.cfg).init(
+        st.cfg, jax.random.PRNGKey(0)))
+    active, frozen = jax.eval_shape(
+        lambda p: split_params(p, st.groups[gi]), params)
+    bundle = jax.eval_shape(st._init_bundle, active)
+    tok = jax.ShapeDtypeStruct((2, 64), jnp.int32, sharding=one_chip)
+    fn, _ = st.build_step(gi)
+    text = jax.jit(fn.__wrapped__).lower(
+        *place((active, frozen, bundle)), {"tokens": tok, "labels": tok},
+        jax.ShapeDtypeStruct((), jnp.float32, sharding=one_chip)
+    ).compile().as_text()
+    assert "fused_adamw_kernel" in text
+    assert 'op_name="jit(step)/update/fused_adamw_kernel' in text
 
 
 def test_flash_attention_compiles_at_qwen2_head_width(one_chip):
