@@ -39,6 +39,12 @@ transfers happen, never what they carry.
 The host/device placement primitives (:func:`host_put`,
 :func:`device_put_async`) live here too; ``repro.core.strategy`` re-exports
 them for compatibility.
+
+Offloads write in place where they can: a revisited group's new bundle is
+stored into the pinned-host buffers that already hold its previous bundle
+(donated to a small compiled store whose output aliases them), so each
+group's host buffers are allocated, and mapped for DMA, once per process
+rather than at every offload.  The host tree passed as ``into`` is consumed.
 """
 from __future__ import annotations
 
@@ -74,7 +80,82 @@ def _leaf_placements(tree: PyTree, memory_kind: str) -> PyTree:
     return jax.tree.map(one, tree)
 
 
-def host_put(tree: PyTree, shardings: PyTree = None) -> PyTree:
+def _host_placements(tree: PyTree, shardings: PyTree = None) -> PyTree:
+    """Where :func:`host_put` places ``tree``: ``shardings`` with the memory
+    kind pinned_host, or each leaf's own placement flipped to it."""
+    if shardings is not None:
+        return jax.tree.map(lambda s: s.with_memory_kind("pinned_host"),
+                            shardings)
+    return _leaf_placements(tree, "pinned_host")
+
+
+def reusable(new: PyTree, old: PyTree, host: PyTree) -> bool:
+    """True when ``old`` can take ``new`` in place: the same tree structure
+    and, leaf by leaf, the same shape and dtype, each ``old`` leaf already
+    placed on ``host`` (a pinned_host placement) and not yet consumed.  A
+    first visit (``old`` None), a bundle restored from a checkpoint (numpy
+    or device-memory leaves) or one of another layout is not."""
+    if old is None:
+        return False
+    new_leaves, treedef = jax.tree.flatten(new)
+    old_leaves, old_def = jax.tree.flatten(old)
+    host_leaves = jax.tree.leaves(host)
+    if old_def != treedef or len(host_leaves) != len(new_leaves):
+        return False
+    return all(getattr(o, "sharding", None) == h and o.shape == n.shape
+               and o.dtype == n.dtype
+               and not (isinstance(o, jax.Array) and o.is_deleted())
+               for n, o, h in zip(new_leaves, old_leaves, host_leaves))
+
+
+def compile_store(new: PyTree, old: PyTree, host: PyTree):
+    """The store ``(new, old) -> host copy of new`` compiled with ``old``
+    donated, so XLA may alias each output onto the ``old`` leaf of the same
+    shape.  ``keep_unused`` keeps ``old`` a parameter of the program: the
+    store never reads it, and jit would otherwise prune it and alias
+    nothing."""
+    return jax.jit(lambda n, o: n, donate_argnums=(1,), keep_unused=True,
+                   out_shardings=host).lower(new, old).compile()
+
+
+def aliases_every_output(compiled) -> bool:
+    """True when every byte a compiled store writes to host memory lands in
+    a donated host argument (``memory_analysis``), so the store allocates
+    no host buffer."""
+    stats = compiled.memory_analysis()
+    out = getattr(stats, "host_output_size_in_bytes", 0)
+    return out > 0 and stats.host_alias_size_in_bytes == out
+
+
+# (bundle structure, leaf shapes/dtypes/placements, host placements) ->
+# the compiled in-place store, or None where XLA gives it no alias: one
+# entry per bundle layout, decided when it is compiled, never per offload
+_STORES: dict = {}
+
+
+def _store(new: PyTree, old: PyTree, host: PyTree):
+    if not reusable(new, old, host):
+        return None
+    leaves, treedef = jax.tree.flatten(new)
+    key = (treedef, tuple((x.shape, x.dtype, x.sharding) for x in leaves),
+           tuple(jax.tree.leaves(host)))
+    if key not in _STORES:
+        compiled = compile_store(new, old, host)
+        _STORES[key] = compiled if aliases_every_output(compiled) else None
+    return _STORES[key]
+
+
+def writes_in_place(tree: PyTree, into: PyTree = None,
+                    shardings: PyTree = None) -> bool:
+    """Whether ``host_put(tree, shardings, into=into)`` stores ``tree`` into
+    ``into``'s buffers rather than into freshly allocated ones."""
+    if jax.devices()[0].platform == "cpu":
+        return False
+    return _store(tree, into, _host_placements(tree, shardings)) is not None
+
+
+def host_put(tree: PyTree, shardings: PyTree = None,
+             into: PyTree = None) -> PyTree:
     """Move a pytree to host memory (the paper's MoveOptimizerState2CPU).
 
     On TPU this uses the pinned_host memory kind so the transfer back is an
@@ -85,16 +166,22 @@ def host_put(tree: PyTree, shardings: PyTree = None) -> PyTree:
     derived per leaf from the tree's CURRENT sharding (memory kind flipped
     to pinned_host) — see :func:`_leaf_placements`.
 
+    ``into`` is the host tree this one replaces (a group's previous
+    bundle).  Where it is :func:`reusable` and the store aliases, ``tree``
+    is written into ``into``'s pinned buffers, which are donated: ``into``
+    must not be read afterwards (a pending upload from it still completes
+    first).  Otherwise the copy goes to freshly allocated pinned buffers,
+    which the runtime must map for DMA.
+
     A backend that refuses pinned_host raises here: keeping multi-GB
     optimizer state on device would silently undo the offload that HiFT's
     memory claim rests on."""
     if jax.devices()[0].platform == "cpu":
         return tree
-    if shardings is not None:
-        host = jax.tree.map(lambda s: s.with_memory_kind("pinned_host"),
-                            shardings)
-    else:
-        host = _leaf_placements(tree, "pinned_host")
+    host = _host_placements(tree, shardings)
+    store = _store(tree, into, host)
+    if store is not None:
+        return store(tree, into)
     return jax.device_put(tree, host)
 
 
@@ -225,7 +312,7 @@ class BundlePipeline:
         self._note_resident()
 
     def offload(self, key: str, new_bundle: PyTree,
-                shardings: PyTree = None) -> PyTree:
+                shardings: PyTree = None, into: PyTree = None) -> PyTree:
         """Deferred host offload of a step's output bundle: the D2H copy is
         DISPATCHED now (it runs once the step finishes, overlapping the next
         step) but this call does not block on it — the device buffer is
@@ -233,11 +320,19 @@ class BundlePipeline:
         enqueueing, older drains are blocked down to ``depth - 2`` entries so
         the NEXT step's device bundle (prefetched or freshly initialized)
         still fits the budget.  Returns the host tree to store in
-        ``TrainState.opt_state``."""
+        ``TrainState.opt_state``.  ``into`` is the group's previous host
+        bundle, written over in place where :func:`host_put` can."""
         while len(self._draining) > max(self.depth - 2, 0):
             self.stats.budget_waits += 1
             jax.block_until_ready(self._draining.popleft())
-        host = host_put(new_bundle, shardings)
+        if into is not None and any(h is into for h in self._draining):
+            # a drain the store is about to donate: wait on it here, since
+            # a donated tree cannot be waited on later
+            self.stats.budget_waits += 1
+            jax.block_until_ready(into)
+            self._draining = deque(h for h in self._draining
+                                   if h is not into)
+        host = host_put(new_bundle, shardings, into=into)
         self._draining.append(host)
         self.stats.offloads += 1
         # the draining buffer IS the step's donated active buffer, so at

@@ -83,7 +83,7 @@ from repro.dist.compress import compress_tree_with_feedback, init_residuals
 from repro.core.grouping import (Group, group_cut, make_groups, merge_params,
                                  order_groups, split_params)
 from repro.core.pipeline import (BundlePipeline, ChunkLayout, ChunkStream,
-                                 device_put_async, host_put)
+                                 device_put_async, host_put, writes_in_place)
 from repro.core.registry import register_strategy
 from repro.core.scheduler import LRSchedule
 from repro.models import get_family, unit_first_depth
@@ -390,8 +390,11 @@ class Strategy:
 
     One caveat: on accelerator backends the jitted steps DONATE the active
     param / optimizer buffers (the k-fold memory reduction depends on it),
-    so the input state is consumed — sequential drivers like ``Runner`` are
-    unaffected, but re-stepping an old state is CPU-only.
+    and a grouped step writes the active group's new host bundle into the
+    pinned host buffers of the input state's bundle, so the input state is
+    consumed, its host bundles included — sequential drivers like
+    ``Runner`` are unaffected (a checkpoint copies the state to host numpy
+    before it returns), but re-stepping an old state is CPU-only.
 
     **Sharding.**  With a multi-device ``mesh`` the steps compile with
     explicit shardings (see module docstring); ``param_sharding_fn(tree,
@@ -842,11 +845,16 @@ class _GroupedStrategy(Strategy):
                         pipe.prefetch(str(ngi), nbundle,
                                       self._bundle_placement(nbundle))
         if self.offload_optimizer:
+            # a revisit writes the new bundle over the previous one in its
+            # pinned host buffers, consuming the input state's bundle
+            old = state.opt_state.get(key)
             with TraceAnnotation("repro.bundle_offload", group=gi,
-                                 bytes=self._nbytes(gi, new_bundle)):
-                new_bundle = (pipe.offload(key, new_bundle, bspec)
+                                 bytes=self._nbytes(gi, new_bundle),
+                                 in_place=int(writes_in_place(
+                                     new_bundle, old, bspec))):
+                new_bundle = (pipe.offload(key, new_bundle, bspec, into=old)
                               if pipe is not None
-                              else host_put(new_bundle, bspec))
+                              else host_put(new_bundle, bspec, into=old))
         opt_state = dict(state.opt_state)
         opt_state[key] = new_bundle
         with TraceAnnotation("repro.write_back", group=gi):

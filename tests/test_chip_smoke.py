@@ -99,7 +99,7 @@ def test_hift_revisit_reuses_the_compiled_step_under_real_offload(
     from repro.core import HiFTConfig, make_runner, pipeline, strategy
 
     def moved(kind):
-        return lambda tree, shardings=None: jax.device_put(
+        return lambda tree, shardings=None, into=None: jax.device_put(
             tree, pipeline._leaf_placements(tree, kind))
 
     for module in (strategy, pipeline):

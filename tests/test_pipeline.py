@@ -15,7 +15,8 @@ import pytest
 from conftest import make_batch, tiny_dense_cfg
 from repro.common.pytree import flatten_with_paths
 from repro.core import (HiFTConfig, LiSAConfig, LRSchedule, make_runner)
-from repro.core.pipeline import BundlePipeline
+from repro.core.pipeline import (BundlePipeline, _host_placements, host_put,
+                                 reusable, writes_in_place)
 from repro.train import checkpoint as ckpt
 
 
@@ -197,3 +198,103 @@ def test_depth_three_lookahead_bitwise_equal():
     stats = deep.strategy._pipeline.stats
     assert stats.prefetch_hits >= serial.k   # lookahead actually served
     assert stats.max_resident <= 3           # never beyond the window
+
+
+# ------------------------------------------------------- in-place offload
+
+def _pinned(tree, **change):
+    """ShapeDtypeStructs of ``tree`` placed in pinned host memory, as a
+    previous offload of the same bundle leaves them on a TPU (the CPU
+    backend cannot compile a pinned-host store, so the choice is tested on
+    placements alone)."""
+    host = _host_placements(tree)
+    return jax.tree.map(lambda x, h: jax.ShapeDtypeStruct(
+        change.get("shape", x.shape), change.get("dtype", x.dtype),
+        sharding=h), tree, host)
+
+
+def _bundle():
+    return {"opt": {"m": jnp.ones((2, 8)), "v": jnp.ones((2, 8)),
+                    "count": jnp.zeros((), jnp.int32)},
+            "master": jnp.full((2, 8), 3.0)}
+
+
+@pytest.mark.parametrize("case,expect", [
+    ("pinned", True), ("first_visit", False), ("device_memory", False),
+    ("numpy", False), ("shape", False), ("dtype", False),
+    ("structure", False), ("deleted", False)])
+def test_offload_reuses_only_a_matching_pinned_host_tree(case, expect):
+    """An offload writes in place only over a tree of the same structure,
+    shapes and dtypes already in pinned host memory where the new bundle
+    goes; anything else (no bundle yet, a restored or device-resident one,
+    another layout, a consumed one) gets fresh buffers."""
+    new = _bundle()
+    old = {"pinned": lambda: _pinned(new),
+           "first_visit": lambda: None,
+           "device_memory": lambda: jax.tree.map(jnp.copy, new),
+           "numpy": lambda: jax.tree.map(np.asarray, new),
+           "shape": lambda: _pinned(new, shape=(3, 8)),
+           "dtype": lambda: _pinned(new, dtype=jnp.bfloat16),
+           "structure": lambda: {"opt": _pinned(new)["opt"]},
+           "deleted": lambda: _deleted(new)}[case]()
+    assert reusable(new, old, _host_placements(new)) is expect
+
+
+def _deleted(tree):
+    out = jax.device_put(tree, _host_placements(tree))
+    for x in jax.tree.leaves(out):
+        x.delete()
+    return out
+
+
+def test_offload_choice_follows_the_runners_bundles(tmp_path):
+    """Fresh at a first visit and after a checkpoint restore (its bundles
+    come back as device arrays), in place over the pinned-host image of
+    the bundle the step replaces.  The CPU backend keeps bundles where
+    they are: ``host_put`` is the identity and consumes nothing."""
+    cfg = tiny_dense_cfg(ce_chunk=0)
+    r = _runner("hift", cfg)
+    for step in range(r.k):
+        r.train_step(make_batch(cfg, batch=2, seq=16, seed=step))
+    key = str(r.strategy._order_at(r.state)[0])
+    bundle = r.state.opt_state[key]
+    new = jax.tree.map(lambda x: x + 0, bundle)      # a step's output
+    host = _host_placements(new)
+    assert not reusable(new, None, host)
+    assert reusable(new, _pinned(bundle), host)
+    ckpt.save_state(tmp_path, r.k, r.state)
+    restored = ckpt.restore_state(tmp_path, r.k).opt_state[key]
+    assert not reusable(new, restored, host)
+    assert not writes_in_place(new, bundle)          # CPU: no store
+    assert host_put(new, into=bundle) is new
+    assert not any(x.is_deleted() for x in jax.tree.leaves(bundle))
+
+
+def test_offload_over_a_draining_bundle_waits_for_it_first():
+    """At depth 3 a group revisited at once offloads over a bundle whose
+    own offload is still draining: the pipeline waits on it and drops it
+    from the drain queue before the store consumes it."""
+    pipe = BundlePipeline(3)
+    first = pipe.offload("0", _bundle())
+    assert list(pipe._draining) == [first]
+    second = pipe.offload("0", _bundle(), into=first)
+    assert [h is second for h in pipe._draining] == [True]
+    assert pipe.stats.budget_waits == 1
+    pipe.flush()
+    assert pipe.device_resident(active=0) == 0
+
+
+def test_pipelined_lisa_revisits_at_depth_three_bitwise_equal():
+    """LiSA keeps one group for ``switch_every`` steps, so at depth 3 each
+    offload lands over a bundle still draining: the trajectory stays
+    bit-identical to the serial schedule."""
+    cfg = tiny_dense_cfg(ce_chunk=0)
+    lisa = LiSAConfig(m=1, switch_every=3, seed=5)
+    serial = _runner("lisa", cfg, lisa=lisa)
+    deep = _runner("lisa", cfg, lisa=lisa, pipeline_depth=3)
+    for step in range(9):
+        batch = make_batch(cfg, batch=2, seq=16, seed=step)
+        assert float(serial.train_step(batch)) == \
+            float(deep.train_step(batch)), step
+    _assert_same(_snap(serial.state), _snap(deep.state), err="lisa3: ")
+    assert deep.strategy._pipeline.stats.max_resident <= 3

@@ -118,3 +118,54 @@ def test_flash_attention_compiles_at_qwen2_head_width(one_chip):
         qkv, qkv, qkv)
     assert cfg.head_dim == 64
     assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_bundle_offload_store_aliases_an_internlm2_block_bundle(one_chip):
+    """The in-place offload of a full-width internlm2 block's Mixed^Hi
+    bundle (fp32 master, AdamW m and v): every leaf of the store's host
+    output is aliased onto the donated host bundle, so the offload
+    allocates no pinned host buffer."""
+    from repro.core.grouping import split_params
+    from repro.core.pipeline import (_host_placements, aliases_every_output,
+                                     compile_store)
+    from repro.core.registry import make_strategy
+    from repro.core.strategy import HiFTConfig
+    from repro.optim import make_optimizer
+    from repro.optim.mixed_precision import get_policy
+
+    cfg = get_config("internlm2_1_8b")
+    st = make_strategy("hift", cfg, make_optimizer("adamw"),
+                       hift=HiFTConfig(m=1), policy=get_policy("mixed_hi"))
+    params = jax.eval_shape(lambda: get_family(cfg).init(
+        cfg, jax.random.PRNGKey(0)))
+    active, _ = jax.eval_shape(lambda p: split_params(p, st.groups[1]),
+                               params)               # layer 0
+    bundle = jax.eval_shape(st._init_bundle, active)
+    new = jax.tree.map(lambda x: jax.ShapeDtypeStruct(
+        x.shape, x.dtype, sharding=one_chip), bundle)
+    host = _host_placements(new)
+    old = jax.tree.map(lambda x, h: jax.ShapeDtypeStruct(
+        x.shape, x.dtype, sharding=h), new, host)
+    compiled = compile_store(new, old, host)
+
+    leaves = jax.tree.leaves(new)
+    big = [x for x in leaves if x.ndim]
+    assert {x.dtype for x in big} == {jnp.dtype(jnp.float32)}
+    assert len(big) == 3 * len(jax.tree.leaves(active))   # master, m, v
+    nbytes = sum(x.size * x.dtype.itemsize for x in leaves)
+    assert nbytes > 700_000_000                  # one full-width block
+    stats = compiled.memory_analysis()
+    assert stats.host_alias_size_in_bytes == stats.host_output_size_in_bytes
+    assert stats.host_alias_size_in_bytes >= nbytes
+    # padding of the one scalar (AdamW's count) is all that is over
+    assert stats.host_alias_size_in_bytes - nbytes < 4096
+    assert aliases_every_output(compiled)
+    # every output leaf aliases its own donated parameter, which lives in
+    # host memory (S(5)): the n device inputs come first, then the n old
+    head = compiled.as_text().split("\n", 1)[0]
+    n = len(leaves)
+    for i in range(n):
+        assert f"{{{i}}}: ({n + i}, {{}}, may-alias)" in head
+    params_layout = head.split("entry_computation_layout={(", 1)[1] \
+        .split(")->", 1)[0]
+    assert params_layout.count("S(5)") == n

@@ -108,6 +108,15 @@ def test_fetch_and_offload_carry_the_group_and_its_bundle_bytes(hift_run):
         assert stats["bytes"] == runner.strategy._bundle_bytes[stats["group"]]
 
 
+def test_offloads_say_whether_they_wrote_in_place(hift_run):
+    """Every offload span carries ``in_place``: 0 here, where the CPU
+    backend keeps a bundle where it is and no store runs."""
+    runner, _, spans, _ = hift_run
+    offloads = [e[3] for e in spans if e[0] == "repro.bundle_offload"]
+    assert len(offloads) == runner.k + 1
+    assert [stats["in_place"] for stats in offloads] == [0] * len(offloads)
+
+
 def test_losses_are_bit_identical_with_the_profiler_on_and_off(hift_run):
     _, (untraced, traced), _, _ = hift_run
     assert traced == untraced
