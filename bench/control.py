@@ -41,17 +41,17 @@ def main(argv=None) -> int:
         print(f"control: {e}", file=sys.stderr)
         return 3
     C.use_compile_cache()
-    c, w, mix = cell.config, cell.workload, cell.traffic
+    w = cell.workload
     for seed in args.seeds:
         t = time.perf_counter()
-        ref = R.replay(c, w, mix, seed)
+        ref = R.replay(cell, seed)
         readings = [(R.CONTROL[w["matmuls"]], None), ("fp32", "half_batch")]
         if w["strategy"] == "hift" and \
-                R.first_steps(c, w) > R.sweep_length(c, w):
+                R.first_steps(cell) > R.sweep_length(cell):
             readings += [("fp32", "revisit_fresh"),
                          ("fp32", "revisit_unchanged")]
         for precision, fault in readings:
-            got = R.replay(c, w, mix, seed, precision=precision, fault=fault)
+            got = R.replay(cell, seed, precision=precision, fault=fault)
             gaps = C.compare(got, ref)
             print(json.dumps({"workload": cell.name, "seed": seed,
                               "reading": fault or f"control_{precision}",

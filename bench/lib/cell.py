@@ -25,12 +25,10 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from bench.lib import flops as FL
 from bench.lib import model as M
 from bench.lib import reference as REF
 from bench.lib import spec as SPEC
 from bench.lib import trace as TR
-from bench.lib.peaks import peaks
 from bench.lib.traffic import MarkovBatches
 
 OUT_DIR = SPEC.BENCH_DIR / ".out"
@@ -94,11 +92,13 @@ class CompileCounter:
 
 # ------------------------------------------------------------------ program
 
-def build_runner(cfg, c: dict, w: dict, seed: int):
+def build_runner(cfg, cell: SPEC.Cell, seed: int):
     from repro.core import HiFTConfig, LRSchedule, make_runner
     from repro.optim.mixed_precision import get_policy
 
-    params = M.init_params(c, seed, REF.PARAM_DTYPE[w["policy"]])
+    w = cell.workload
+    params = M.init_params(cell.family.param_shapes(cell.config), seed,
+                           REF.PARAM_DTYPE[w["policy"]])
     kw = {"schedule": LRSchedule(base_lr=float(w["lr"]), kind="constant"),
           "policy": get_policy(w["policy"])}
     if w["strategy"] == "hift":
@@ -129,13 +129,12 @@ def _on_device(tree):
         lambda x: x.sharding.with_memory_kind("device"), tree))
 
 
-def _grads(m_tree: dict, layer=None) -> tuple:
+def _grads(m_tree: dict, layer=None, stacked=()) -> tuple:
     """Norms and samples of the first gradients, from AdamW's first moment
     after one update: m = (1 - b1) g."""
     scale = 1.0 / (1.0 - REF.ADAMW["b1"])
-    return ({k: v * scale for k, v in REF.named_norms(m_tree, layer).items()},
-            {k: v * scale for k, v in REF.named_samples(m_tree,
-                                                        layer).items()})
+    return tuple({k: v * scale for k, v in f(m_tree, layer, stacked).items()}
+                 for f in (REF.named_norms, REF.named_samples))
 
 
 def _diff(a, b):
@@ -143,17 +142,14 @@ def _diff(a, b):
                         - y.astype(jnp.float32), a, b)
 
 
-def _bundles(runner, c: dict):
-    """``(key, layer, bundle)`` of each HiFT group that has a bundle: the
-    parameters' top-level key, the block's index (``None`` for the
-    embedding and the head) and its bundle, wherever the program keeps
-    it."""
-    n = c["num_hidden_layers"]
-    for gi in range(n + 2):
+def _bundles(runner, cell: SPEC.Cell):
+    """``(key, layer, bundle)`` of each HiFT group that has a bundle: its
+    unit's top-level key, the block's index in that stacked key (``None``
+    for a whole key) and its bundle, wherever the program keeps it."""
+    for gi, (key, layer) in enumerate(REF.hift_units(cell)):
         bundle = runner.state.opt_state.get(str(gi))
         if bundle is not None:
-            yield (("embed", None) if gi == 0 else ("head", None)
-                   if gi == n + 1 else ("layers", gi - 1)) + (bundle,)
+            yield key, layer, bundle
 
 
 def _part(tree: dict, key: str, layer):
@@ -163,13 +159,14 @@ def _part(tree: dict, key: str, layer):
     return sub if layer is None else jax.tree.map(lambda x: x[0], sub)
 
 
-def first_grads(runner, c: dict, w: dict) -> tuple:
+def first_grads(runner, cell: SPEC.Cell) -> tuple:
     """Norms and samples of each trained leaf's first gradient, read from
     AdamW's first moment once every group has had one update."""
-    if w["strategy"] != "hift":
-        return _grads(runner.state.opt_state["m"])
+    if cell.workload["strategy"] != "hift":
+        return _grads(runner.state.opt_state["m"],
+                      stacked=cell.family.stacked(cell.config))
     norms, samples = {}, {}
-    for key, layer, bundle in _bundles(runner, c):
+    for key, layer, bundle in _bundles(runner, cell):
         m = _part(_on_device(bundle["opt"]["m"]), key, layer)
         n, s = _grads({key: m}, layer)
         norms.update(n)
@@ -178,17 +175,20 @@ def first_grads(runner, c: dict, w: dict) -> tuple:
     return norms, samples
 
 
-def last_readings(runner, c: dict, w: dict, seed: int) -> dict:
+def last_readings(runner, cell: SPEC.Cell, seed: int) -> dict:
     """The norms of each trained leaf's change since the seed and, under
     HiFT, of its first moment: from each group's optimizer bundle (its
     float32 master under Mixed^Hi) after the revisits, or from FPFT's
     parameters."""
-    p0 = M.init_params(c, seed, REF.PARAM_DTYPE[w["policy"]])
+    fam, c, w = cell.family, cell.config, cell.workload
+    p0 = M.init_params(fam.param_shapes(c), seed,
+                       REF.PARAM_DTYPE[w["policy"]])
     params = runner.state.params
     if w["strategy"] != "hift":
-        return {"change": REF.named_norms(_diff(params, p0))}
+        return {"change": REF.named_norms(_diff(params, p0),
+                                          stacked=fam.stacked(c))}
     out = {"change": {}, "moment": {}}
-    for key, layer, bundle in _bundles(runner, c):
+    for key, layer, bundle in _bundles(runner, cell):
         m = _part(_on_device(bundle["opt"]["m"]), key, layer)
         if "master" in bundle:
             new = _part(_on_device(bundle["master"]), key, layer)
@@ -270,13 +270,13 @@ def run(cell: SPEC.Cell, seed: int, seconds: float, trace: bool, *,
     counter = CompileCounter()
 
     c, w, mix = cell.config, cell.workload, cell.traffic
-    cfg = M.arch_config(c)
+    cfg = cell.family.arch_config(c)
     gen = MarkovBatches(mix, c["vocab_size"], seed)
-    sweep = REF.sweep_length(c, w) if w["strategy"] == "hift" else 1
-    warm = REF.first_steps(c, w)
+    sweep = REF.sweep_length(cell)
+    warm = REF.first_steps(cell)
     check_s = 0.0
 
-    runner = build_runner(cfg, c, w, seed)
+    runner = build_runner(cfg, cell, seed)
     if plant is not None:
         plant(runner)
     prog = {"loss": []}
@@ -289,11 +289,11 @@ def run(cell: SPEC.Cell, seed: int, seconds: float, trace: bool, *,
         if step == sweep - 1:           # every group has had one update
             jax.block_until_ready(runner.state)
             t = time.perf_counter()
-            prog["grad"], prog["grad_sample"] = first_grads(runner, c, w)
+            prog["grad"], prog["grad_sample"] = first_grads(runner, cell)
             check_s += time.perf_counter() - t
     jax.block_until_ready(runner.state)
     t = time.perf_counter()
-    prog.update(last_readings(runner, c, w, seed))
+    prog.update(last_readings(runner, cell, seed))
     jax.block_until_ready(runner.state)
     check_s += time.perf_counter() - t
     setup_s = process_age_s() - check_s
@@ -309,7 +309,8 @@ def run(cell: SPEC.Cell, seed: int, seconds: float, trace: bool, *,
         jax.profiler.start_trace(str(trace_dir))
     if trace:
         seconds = min(seconds, TRACE_SECONDS)
-    groups = FL.groups(c, int(w["m"])) if w["strategy"] == "hift" else [None]
+    groups = (M.groups(cell.family.units(c), int(w["m"]))
+              if w["strategy"] == "hift" else [None])
     attempted = failed = 0
     window_groups, step_ends = [], []
     step = warm
@@ -363,7 +364,8 @@ def run(cell: SPEC.Cell, seed: int, seconds: float, trace: bool, *,
               "memory_peak_bytes": peak}
     result = {"correct": False, "attempted": attempted, "failed": failed,
               "metrics": {}, "device": device}
-    facts = {"config": c, "workload": w, "traffic": mix,
+    facts = {"config": c, "family": cell.family, "workload": w,
+             "traffic": mix,
              "window_groups": window_groups, "window_s": window_s,
              "device_kind": dev.device_kind, "trace": None,
              "resident_bytes": resident_bytes(runner.state)}
@@ -390,7 +392,7 @@ def run(cell: SPEC.Cell, seed: int, seconds: float, trace: bool, *,
     del runner
     gc.collect()
     t = time.perf_counter()
-    ref = REF.replay(c, w, mix, seed)
+    ref = REF.replay(cell, seed)
     gaps = compare(prog, ref)
     say(f"reference: {time.perf_counter() - t} s for {warm} steps")
     limits = w["limits"]

@@ -97,9 +97,9 @@ def test_a_broken_revisit_is_not_correct(fault):
 @pytest.mark.parametrize("name", CELLS)
 def test_the_control_is_not_correct(name):
     cell = tiny_cell(name)
-    c, w, mix = cell.config, cell.workload, cell.traffic
-    ref = R.replay(c, w, mix, SEED)
-    low = R.replay(c, w, mix, SEED, precision=R.CONTROL[w["matmuls"]])
+    w = cell.workload
+    ref = R.replay(cell, SEED)
+    low = R.replay(cell, SEED, precision=R.CONTROL[w["matmuls"]])
     checks = {k: {"value": v, "limit": w["limits"][k]}
               for k, (v, _) in C.compare(low, ref).items()}
     assert _failing(checks), checks

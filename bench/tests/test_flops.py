@@ -1,9 +1,11 @@
 """Operations and bytes from shapes, against hand arithmetic."""
 import pytest
 
-from bench.lib import flops as F
 from bench.lib import spec
+from bench.lib.model import groups
 from bench.lib.peaks import PEAKS, peaks
+
+F = spec.family("dense")
 
 B, S = 8, 512
 T = B * S
@@ -39,7 +41,7 @@ def test_top_group_trains_the_head_only(internlm):
     fwd = 16 * (2 * n_l * T + _attn(internlm)) + head
     # backward: the loss's gradient into the final hidden state (for the
     # norm's scale) and the head weight's gradient; no block is crossed
-    assert F.step_flops(internlm, B, S, ["head"]) == fwd + head + head
+    assert F.step_flops(internlm, B, S, [("head", None)]) == fwd + head + head
 
 
 def test_layer_group_crosses_the_blocks_above_its_cut(internlm):
@@ -48,7 +50,7 @@ def test_layer_group_crosses_the_blocks_above_its_cut(internlm):
     a = _attn(internlm)
     fwd = 16 * (2 * n_l * T + a) + head
     act = head + (16 - 5) * (2 * n_l * T + 2 * a)
-    assert F.step_flops(internlm, B, S, [("layer", 5)]) == \
+    assert F.step_flops(internlm, B, S, [("layers", 5)]) == \
         fwd + act + 2 * n_l * T
 
 
@@ -58,7 +60,7 @@ def test_embed_group_crosses_every_block_and_has_no_matmul_weight(internlm):
     a = _attn(internlm)
     fwd = 16 * (2 * n_l * T + a) + head
     act = head + 16 * (2 * n_l * T + 2 * a)
-    assert F.step_flops(internlm, B, S, ["embed"]) == fwd + act
+    assert F.step_flops(internlm, B, S, [("embed", None)]) == fwd + act
 
 
 def test_fpft_is_6nt_plus_attention(qwen, internlm):
@@ -70,9 +72,11 @@ def test_fpft_is_6nt_plus_attention(qwen, internlm):
 
 
 def test_groups_partition_the_units(internlm):
-    gs = F.groups(internlm, 1)
-    assert len(gs) == 18 and gs[0] == ["embed"] and gs[-1] == ["head"]
-    assert F.groups(internlm, 6)[1] == [("layer", i) for i in range(5, 11)]
+    gs = groups(F.units(internlm), 1)
+    assert len(gs) == 18 and gs[0] == [("embed", None)] \
+        and gs[-1] == [("head", None)]
+    assert groups(F.units(internlm), 6)[1] == [("layers", i)
+                                               for i in range(5, 11)]
 
 
 def test_peaks_table_names_its_source_and_refuses_unknown_kinds():

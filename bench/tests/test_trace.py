@@ -53,16 +53,16 @@ def test_span_window_finds_the_window_span(tr):
 
 def test_metric_readers_on_the_recorded_trace(tr):
     from bench.lib import spec
+    dense = spec.family("dense")
     facts = {"trace": tr, "config": spec.config("internlm2_1_8b-l16"),
-             "workload": {"policy": "mixed_hi"},
+             "family": dense, "workload": {"policy": "mixed_hi"},
              "traffic": {"batch": 8, "seq": 512},
-             "window_groups": [["head"]], "window_s": 1e-6,
+             "window_groups": [[("head", None)]], "window_s": 1e-6,
              "device_kind": "TPU v5 lite"}
     assert spec.metric_reader("device_idle_share")(facts) == \
         pytest.approx(55.0)
-    from bench.lib import flops
-    want = 100 * flops.step_flops(facts["config"], 8, 512, ["head"]) \
-        / (1e-6 * 197e12)
+    want = 100 * dense.step_flops(facts["config"], 8, 512,
+                                  [("head", None)]) / (1e-6 * 197e12)
     assert spec.metric_reader("step_mfu")(facts) == pytest.approx(want)
     empty = {**facts, "trace": {**tr, "device": []}}
     assert spec.metric_reader("device_idle_share")(empty) is None

@@ -74,11 +74,12 @@ def test_hift_layer0_group_fits_one_chip(one_chip, monkeypatch):
                         False)
     cell = spec.cell("internlm2-l16-hift-b8s512")
     c, w = cell.config, cell.workload
-    st = make_strategy("hift", M.arch_config(c),
+    st = make_strategy("hift", cell.family.arch_config(c),
                        make_optimizer("adamw", use_pallas_fused=True),
                        hift=HiFTConfig(m=w["m"], strategy=w["order"]),
                        policy=get_policy(w["policy"]))
-    params = jax.eval_shape(lambda: M.init_params(c, 0, jnp.bfloat16))
+    params = jax.eval_shape(lambda: M.init_params(
+        cell.family.param_shapes(c), 0, jnp.bfloat16))
     gi = 1                                   # layer 0: the deepest backward
     active, frozen = jax.eval_shape(
         lambda p: split_params(p, st.groups[gi]), params)
@@ -102,9 +103,10 @@ def test_fpft_step_fits_one_chip(one_chip):
     cell = spec.cell("qwen2-fpft-b8s512")
     c, w = cell.config, cell.workload
     opt = make_optimizer(w["optimizer"])
-    params = jax.eval_shape(lambda: M.init_params(c, 0, jnp.float32))
+    params = jax.eval_shape(lambda: M.init_params(
+        cell.family.param_shapes(c), 0, jnp.float32))
     opt_state = jax.eval_shape(opt.init, params)
-    step = jax.jit(fpft_step_body(M.arch_config(c), opt,
+    step = jax.jit(fpft_step_body(cell.family.arch_config(c), opt,
                                   get_policy(w["policy"])),
                    donate_argnums=(0, 1))
     compiled = step.lower(

@@ -20,9 +20,13 @@ TINY_LIMITS = {"internlm2-l16-hift-b8s512": {"loss_gap": 1e-3,
 
 
 def tiny_cell(name: str, **workload) -> spec.Cell:
-    cell = spec.cell(name)
+    return tiny(spec.cell(name), **workload)
+
+
+def tiny(cell: spec.Cell, **workload) -> spec.Cell:
+    """``cell`` cut to the tiny size, its family and files as they are."""
     mix = {**cell.traffic, "batch": 4, "seq": 32}
-    limits = {**cell.workload["limits"], **TINY_LIMITS.get(name, {})}
+    limits = {**cell.workload["limits"], **TINY_LIMITS.get(cell.name, {})}
     return dataclasses.replace(
         cell, config={**cell.config, **TINY}, traffic=mix,
         workload={**cell.workload, "limits": limits, **workload})
